@@ -1,0 +1,109 @@
+"""What the closed loop drives: the program's tbls entry point, or, for the
+control and the fault tests, the reference put in the program's place.
+
+A system turns a Request into the arguments of its call in set-up
+(`prepare`), and `submit(args)` returns a Future of the set's verdict.
+"""
+
+from __future__ import annotations
+
+import random
+from concurrent.futures import Future
+
+from reference import ref
+
+SAMPLE = 8  # the control verifies one partial in this many
+
+
+def _resolved(fn, *args) -> Future:
+    fut: Future = Future()
+    try:
+        fut.set_result(fn(*args))
+    except Exception as exc:  # noqa: BLE001 — the future carries it
+        fut.set_exception(exc)
+    return fut
+
+
+class ProgramSystem:
+    """The TPU backend of charon_tpu's tbls facade, installed as the node
+    installs it (feature tpu_bls), with device faults raised rather than
+    served from the native fallback."""
+
+    name = "program"
+
+    def __init__(self, chips: int):
+        from charon_tpu import tbls
+        from charon_tpu.app.app import Config, _select_tbls_backend
+        from charon_tpu.ops import mesh as mesh_mod
+        from charon_tpu.ops import pallas_plane as PP
+        from charon_tpu.tbls.tpu_impl import TPUImpl
+
+        if chips == 1:
+            mesh_mod.set_override(1)  # one chip, even on a four-chip host
+        if not _select_tbls_backend(Config(feature_set_enable=["tpu_bls"])):
+            raise RuntimeError("tpu_bls did not select the device path")
+        impl = tbls.get_implementation()
+        if not isinstance(impl, TPUImpl):
+            raise RuntimeError(f"tbls backend is {type(impl).__name__}")
+        impl.fallback_on_device_error = False
+        if PP._interpret():
+            raise RuntimeError("Pallas kernels would run interpreted")
+        if mesh_mod.device_count() != chips:
+            raise RuntimeError(f"mesh is {mesh_mod.device_count()} wide, the "
+                               f"cell asks for {chips}")
+        self.tbls = tbls
+
+    def prepare(self, req):
+        from charon_tpu.tbls.types import PublicKey, Signature
+
+        return ([PublicKey(p) for p in req.pks], list(req.roots),
+                [Signature(s) for s in req.sigs])
+
+    def submit(self, args) -> Future:
+        return _resolved(lambda: bool(self.tbls.verify_batch(*args)))
+
+    def close(self) -> None:
+        pass
+
+
+class ReferenceSystem:
+    """The reference in the program's place.
+
+    `control` makes it the control: it verifies one partial in SAMPLE, from
+    an offset drawn from `seed`, which breaks the guarantee that every
+    partial is verified. `fault` plants one of the faults a timed path can
+    have: "stale" (returns the previous request's answer), "half" (leaves
+    out the second half of the set), "altered" (alters the answer where it
+    is made)."""
+
+    name = "reference"
+
+    def __init__(self, control: bool = False, fault: str | None = None,
+                 seed: int = 0):
+        self.step = SAMPLE if control else 1
+        self.fault = fault
+        self.rng = random.Random(seed)
+        self.last = None
+
+    def prepare(self, req):
+        return req
+
+    def _answer(self, req) -> bool:
+        n = req.size // 2 if self.fault == "half" else req.size
+        ok = all(ref.verifies(req.sigs[i], req.secrets[i], req.roots[i])
+                 for i in range(self.rng.randrange(self.step), n, self.step))
+        return not ok if self.fault == "altered" else ok
+
+    def submit(self, req) -> Future:
+        def call():
+            ans = self._answer(req)
+            if self.fault == "stale" and self.last is not None:
+                ans, self.last = self.last, ans
+            else:
+                self.last = ans
+            return ans
+
+        return _resolved(call)
+
+    def close(self) -> None:
+        pass
