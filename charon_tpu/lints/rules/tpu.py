@@ -506,7 +506,7 @@ class FieldPlaneRoutingRule:
                 f"`{callee}` outside the curve._mont_mul seam forks the "
                 "field plane: the call ignores CHARON_TPU_FIELD_PLANE, "
                 "escapes the XLA-vs-Pallas bit-identity oracle, and can't "
-                "be A/B'd by bench_stages --field-plane; route the product "
+                "be A/B'd by flipping the plane; route the product "
                 "through ops.curve._mont_mul (or _fq_mul_many)")
 
 

@@ -34,6 +34,7 @@ import numpy as np
 from ..crypto import fields as PF
 from ..crypto import hash_to_curve as HH
 from ..crypto.curve import H_EFF_G2
+from ..utils import tracer
 from . import buckets as BK
 from . import field as F
 from . import pallas_plane as PP
@@ -310,8 +311,9 @@ def hash_to_g2_device(msgs, dst: bytes = DST_ETH):
         chunk = msgs[lo:hi]
         u0, u1, s0, s1 = hash_to_field_planes(chunk, dst)
         hx, hy = map_to_g2_device(u0, u1, s0, s1)
-        outs.append((np.asarray(hx)[:len(chunk)],
-                     np.asarray(hy)[:len(chunk)]))
+        with tracer.start_span("ops/device_wait", program="map_to_g2"):
+            outs.append((np.asarray(hx)[:len(chunk)],
+                         np.asarray(hy)[:len(chunk)]))
     return (np.concatenate([o[0] for o in outs]),
             np.concatenate([o[1] for o in outs]))
 

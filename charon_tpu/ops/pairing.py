@@ -33,6 +33,7 @@ import numpy as np
 
 from ..crypto import curve as PC
 from ..crypto import fields as PF
+from ..utils import tracer
 from . import buckets
 from . import field as F
 from . import tower as T
@@ -405,7 +406,7 @@ def fold_chunks_is_one(parts) -> bool:
         f = parts[0]
         ok = _compiled_chunk_finish(c0.shape[0])(
             *f[0], *f[1], jnp.asarray(mask))
-        return bool(np.asarray(ok).reshape(-1)[0])
+        return _wait_verdict(ok, "chunk_finish")
     Kp = _bucket_pairs(k)
     f = _fq12_concat(parts)
     mask = buckets.live_mask(k, Kp)
@@ -417,7 +418,7 @@ def fold_chunks_is_one(parts) -> bool:
 
     cs = [padf(c) for c in (*f[0], *f[1])]
     ok = _compiled_chunk_finish(Kp)(*cs, jnp.asarray(mask))
-    return bool(np.asarray(ok).reshape(-1)[0])
+    return _wait_verdict(ok, "chunk_finish")
 
 
 def _pairing_check_chunked(p_x, p_y, q_x, q_y) -> bool:
@@ -451,7 +452,14 @@ def pairing_check_planes(p_x, p_y, q_x, q_y) -> bool:
                 jnp.asarray(_pad_lane0(np.asarray(q_x), Bp, n)),
                 jnp.asarray(_pad_lane0(np.asarray(q_y), Bp, n)),
                 jnp.asarray(mask))
-    return bool(np.asarray(ok).reshape(-1)[0])
+    return _wait_verdict(ok, "pairing_check")
+
+
+def _wait_verdict(ok, program: str) -> bool:
+    """Block on a check program's one-lane verdict: the host's wait for the
+    chip, as an ops/device_wait span naming the jitted function."""
+    with tracer.start_span("ops/device_wait", program=program):
+        return bool(np.asarray(ok).reshape(-1)[0])
 
 
 def warm_check_buckets(buckets=(2,)) -> int:

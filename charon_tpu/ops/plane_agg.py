@@ -60,21 +60,24 @@ from . import sentinel
 _MONT_ONE = F.fq_from_int(1)
 
 # Dispatch-phase latency split of the fused sigagg slot: "pack" is host
-# parse + async dispatch (_fused_dispatch), "execute" is the explicit
-# block_until_ready fence on the device graph, "drain" is the readback
-# transfer after the fence, "finish" is the pure-host back half (emit
-# bytes + RLC folds, _fused_host_finish) and "verify" is the slot's
+# parse + async dispatch (_fused_dispatch), "execute" is the host's wait on
+# the explicit block_until_ready fence (not device time: a graph that
+# finished while the host packed the next slot waits ~0), "drain" is the
+# readback transfer after the fence, "finish" is the pure-host back half
+# (emit bytes + RLC folds, _fused_host_finish) and "verify" is the slot's
 # RLC-folded pairing check (_pairing_finish — one batched device dispatch
 # of h2c + multi-Miller-loop + final-exp on the device path, the ctypes
-# native rung behind the guard otherwise). finish/verify are the stages
-# the pipeline overlaps on its worker executor. Sub-second buckets — a
-# steady-state slot is ~0.1-0.3 s end to end.
+# native rung behind the guard otherwise; bulk verifies observe it too).
+# finish/verify are the stages the pipeline overlaps on its worker
+# executor. Buckets run past the 12 s slot: a verify that hashes a new
+# root to the curve takes ~7.5 s on one v5e.
 _dispatch_hist = metrics.histogram(
     "ops_device_dispatch_seconds",
-    "Fused sigagg dispatch phases: host pack, device execute, drain-side "
-    "readback transfer, host finish, pairing verify", ("phase",),
+    "Fused sigagg dispatch phases: host pack, host wait on the device "
+    "fence (execute), drain-side readback transfer, host finish, pairing "
+    "verify", ("phase",),
     buckets=(0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
-             0.5, 1, 2.5, 5))
+             0.5, 1, 2.5, 5, 10, 15, 30))
 
 # Stage-3 (host finish) slots scheduled on the pipeline executor but not
 # yet completed — a persistently high value means the finish stage is the
@@ -485,7 +488,8 @@ def _g1_plane_device(pks: list[bytes], Bp: int,
     Xr = jnp.asarray(_raw_to_plane(body, Bp))
     X, Y, Z, ok = _g1_decompress_jit(Xr, jnp.asarray(sgn),
                                      jnp.asarray(loaded))
-    okm = np.asarray(ok)
+    with tracer.start_span("ops/device_wait", program="_g1_decompress_jit"):
+        okm = np.asarray(ok)
     if not okm.all():
         _raise_bad(okm, "G1")
     return PP.PlanePoint(X, Y, Z, 1, Bp)
@@ -676,7 +680,9 @@ def g2_subgroup_ok(p: PP.PlanePoint) -> bool:
     """True iff EVERY loaded element lies in the r-subgroup (padding/∞ pass).
     Matches native g2_in_subgroup (psi(P) == [x]P, bls12381.cpp:800); runs
     as one compiled dispatch."""
-    return bool(_g2_subgroup_jit(p.X, p.Y, p.Z))
+    ok = _g2_subgroup_jit(p.X, p.Y, p.Z)
+    with tracer.start_span("ops/device_wait", program="_g2_subgroup_jit"):
+        return bool(ok)
 
 
 @jax.jit
@@ -700,7 +706,9 @@ def _g1_subgroup_core(X, Y, Z):
 def g1_subgroup_ok(p: PP.PlanePoint) -> bool:
     """True iff every loaded element lies in the r-subgroup; matches native
     g1_in_subgroup (phi(P) == [s·u²]P, bls12381.cpp:814); one dispatch."""
-    return bool(_g1_subgroup_jit(p.X, p.Y, p.Z))
+    ok = _g1_subgroup_jit(p.X, p.Y, p.Z)
+    with tracer.start_span("ops/device_wait", program="_g1_subgroup_jit"):
+        return bool(ok)
 
 
 def _conj_plane(a):
@@ -1859,8 +1867,9 @@ def rlc_verify_batch(pks: list[bytes], msgs: list[bytes], sigs: list[bytes],
     # per-chunk RLC partial sums combine on the host with K-1 Jacobian
     # adds (the RLC equation is a sum; splitting lanes splits the sum).
     # Nothing ever compiles at >TILE lanes.
-    state = rlc_verify_dispatch(pks, msgs, sigs)
-    return rlc_verify_finish(state, hash_fn)
+    with sentinel.region("verify"):
+        state = rlc_verify_dispatch(pks, msgs, sigs)
+        return rlc_verify_finish(state, hash_fn)
 
 
 def rlc_verify_dispatch(pks, msgs, sigs):
@@ -1872,34 +1881,39 @@ def rlc_verify_dispatch(pks, msgs, sigs):
     n = len(msgs)
     chunks = ([(0, n)] if n <= PP.TILE else
               [(s, min(s + PP.TILE, n)) for s in range(0, n, PP.TILE)])
-    # distinct-message groups are GLOBAL so chunk g-indices agree
-    index = _group_index(msgs)
-    _gidx, G, group_msgs = index
     pending = []
     try:
         # every chunk's plane is keyed on the FULL-set digest + span in the
         # PlaneStore — a fixed peer set decodes once per process, not once
         # per slot (the old per-chunk `pks[s:e]` content keys churned the
-        # whole-set-sized LRU every slot, ADVICE round 5)
+        # whole-set-sized LRU every slot, ADVICE round 5). The span covers
+        # the digest, the lookup and, on a miss, the decode with its waits.
         from . import plane_store
 
-        pk_planes = plane_store.STORE.chunk_planes(
-            [bytes(p) for p in pks], chunks)
-        for ci, (s, e) in enumerate(chunks):
-            nc = e - s
-            Bc = _bucket(nc)
-            body, _fin, sgn, loaded = _parse_compressed(
-                sigs[s:e], 96, "G2", True, Bc)
-            pk_plane = pk_planes[ci]
-            X0r = jnp.asarray(_raw_to_plane(body[:, 48:], Bc))
-            X1r = jnp.asarray(_raw_to_plane(body[:, :48], Bc))
-            rdig = jnp.asarray(PP.scalars_to_digitplanes(
-                sample_randomizers(nc), Bc, nbits=RLC_BITS))
-            _keys, gmask = _group_masks(msgs[s:e], nc, Bc, index=index)
-            pending.append(_verify_slot_jit(
-                X0r, X1r, jnp.asarray(sgn), jnp.asarray(loaded), rdig,
-                pk_plane.X, pk_plane.Y, pk_plane.Z, jnp.asarray(gmask),
-                G=G))
+        with tracer.start_span("ops/verify/pk_planes", chunks=len(chunks)):
+            pk_planes = plane_store.STORE.chunk_planes(
+                [bytes(p) for p in pks], chunks)
+        # host work up to the launch of each chunk's verify program
+        with tracer.start_span("ops/verify/pack", lanes=n) as span:
+            # distinct-message groups are GLOBAL so chunk g-indices agree
+            index = _group_index(msgs)
+            _gidx, G, group_msgs = index
+            span.attrs["groups"] = G
+            for ci, (s, e) in enumerate(chunks):
+                nc = e - s
+                Bc = _bucket(nc)
+                body, _fin, sgn, loaded = _parse_compressed(
+                    sigs[s:e], 96, "G2", True, Bc)
+                pk_plane = pk_planes[ci]
+                X0r = jnp.asarray(_raw_to_plane(body[:, 48:], Bc))
+                X1r = jnp.asarray(_raw_to_plane(body[:, :48], Bc))
+                rdig = jnp.asarray(PP.scalars_to_digitplanes(
+                    sample_randomizers(nc), Bc, nbits=RLC_BITS))
+                _keys, gmask = _group_masks(msgs[s:e], nc, Bc, index=index)
+                pending.append(_verify_slot_jit(
+                    X0r, X1r, jnp.asarray(sgn), jnp.asarray(loaded), rdig,
+                    pk_plane.X, pk_plane.Y, pk_plane.Z, jnp.asarray(gmask),
+                    G=G))
     except ValueError:
         return ("invalid",)
     return ("pending", G, group_msgs, pending)
@@ -1913,16 +1927,21 @@ def rlc_verify_finish(state, hash_fn=None) -> bool:
     _tag, G, group_msgs, pending = state
     S = None
     Pg: list = [None] * G
-    for outs in pending:
-        ok, sub_ok, sig_red, pk_reds = jax.device_get(outs)
-        if not (ok.all() and sub_ok):
-            return False
-        sc = PP._host_fold(*sig_red, 2)
-        S = sc if S is None else jac_add(Fq2Ops, S, sc)
-        for g in range(G):
-            pc = PP._host_fold(*pk_reds[g], 2)
-            Pg[g] = pc if Pg[g] is None else jac_add(Fq2Ops, Pg[g], pc)
-    pts = [(m, _unembed_g1(Pg[g])) for g, m in enumerate(group_msgs)]
+    # the host folds of the chunks' reductions into one Jacobian sum each;
+    # the wait for each chunk's program is the nested ops/device_wait
+    with tracer.start_span("ops/verify/fold", chunks=len(pending)):
+        for outs in pending:
+            with tracer.start_span("ops/device_wait",
+                                   program="_verify_slot_jit"):
+                ok, sub_ok, sig_red, pk_reds = jax.device_get(outs)
+            if not (ok.all() and sub_ok):
+                return False
+            sc = PP._host_fold(*sig_red, 2)
+            S = sc if S is None else jac_add(Fq2Ops, S, sc)
+            for g in range(G):
+                pc = PP._host_fold(*pk_reds[g], 2)
+                Pg[g] = pc if Pg[g] is None else jac_add(Fq2Ops, Pg[g], pc)
+        pts = [(m, _unembed_g1(Pg[g])) for g, m in enumerate(group_msgs)]
     return _pairing_finish(S, pts, hash_fn)
 
 
@@ -2165,6 +2184,11 @@ def hash_to_g2_planes(msgs):
     "hit"; misses compute the hash — ONE bucketed device h2c dispatch for
     the whole miss set when the device verify path is up, the native
     bytes rung plus host decompress otherwise — and store both forms."""
+    with tracer.start_span("ops/verify/h2c") as span:
+        return _hash_to_g2_planes(msgs, span)
+
+
+def _hash_to_g2_planes(msgs, span):
     from ..crypto.serialize import g2_affine_to_bytes
 
     B = len(msgs)
@@ -2185,6 +2209,7 @@ def hash_to_g2_planes(msgs):
                 derive.append((i, key, entry[0]))
             else:
                 hx[i], hy[i] = entry[1]
+    span.attrs.update(hits=B - len(missing), misses=len(missing))
     if B - len(missing):
         _h2c_counter.inc("hit", amount=float(B - len(missing)))
     for i, key, comp in derive:
@@ -2292,7 +2317,8 @@ def _pairing_finish(S, group_points, hash_fn=None, plan=None) -> bool:
     dispatching slot's sharded_plane.HostPlan: threaded into the device
     check so a multi-host cluster verify exchanges under the slot's own
     sequence tag (worker threads race; tags must not be call-ordered)."""
-    with _dispatch_hist.observe_time("verify"):
+    with _dispatch_hist.observe_time("verify"), \
+            tracer.start_span("ops/verify/pairing") as span:
         live = []
         for m, P in group_points:
             if jac_is_infinity(FqOps, P):
@@ -2301,6 +2327,7 @@ def _pairing_finish(S, group_points, hash_fn=None, plan=None) -> bool:
                 # still has to balance, so simply omit the vanished pair
                 continue
             live.append((bytes(m), P))
+        span.attrs["pairs"] = len(live) + 1
         if jac_is_infinity(Fq2Ops, S):
             # all signatures were infinity: valid only if every pk side
             # vanished too
@@ -2318,7 +2345,9 @@ def _pairing_finish(S, group_points, hash_fn=None, plan=None) -> bool:
                     guard.note_verify_fallback(reason, exc)
                 else:
                     _pairing_c.inc("device", amount=float(len(live) + 1))
+                    span.attrs["path"] = "device"
                     return ok
+        span.attrs["path"] = "native"
         return _native_pairing_finish(S, live, hash_fn)
 
 

@@ -159,6 +159,7 @@ class PlaneStore:
                     out[i] = plane
             for i, s, e, Bc in missing:
                 _misses.inc("device")
+            tracer.set_attrs(misses=len(missing))
             if missing:
                 for (i, s, e, Bc), plane in zip(
                         missing, self._decode_chunks(pks, missing)):

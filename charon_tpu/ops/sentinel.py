@@ -18,11 +18,14 @@ module makes the invariant observable and enforced:
     and compiler modules emit.
 
   * Every observed compile increments ops_jit_compiles_total{region}.
-    Compile events carry no useful metadata (the monitoring kwargs are
-    empty), so the region label comes from the thread-local region()
-    context the warm paths and the slot pipeline wrap themselves in —
+    The region label comes from the thread-local region() context the warm
+    paths, the slot pipeline and the bulk verify wrap themselves in —
     "warm" during AOT warmup, "slot" inside SigAggPipeline dispatch,
-    "other" when nobody declared a region.
+    "verify" inside plane_agg.rlc_verify_batch's device path, "other" when
+    nobody declared a region. Each backend compile (persistent-cache loads
+    included) also adds a `jax_compile` event (fun, seconds, region) to the
+    tracer span open in the compiling thread, so a recompile inside a
+    served duty shows on that duty's span in the flight recorder.
 
   * steady_state() arms a process-global armed-window flag and (in the
     entering thread) jax.transfer_guard("disallow"). Any compile observed
@@ -52,7 +55,7 @@ import os
 import threading
 from typing import Iterator
 
-from ..utils import log, metrics
+from ..utils import log, metrics, tracer
 
 _log = log.with_topic("sentinel")
 
@@ -101,7 +104,7 @@ def region(name: str) -> Iterator[None]:
             _tls.region = prev
 
 
-def _on_compile(reg: str | None = None) -> None:
+def _on_compile(reg: str | None = None) -> str:
     global _total, _steady_total
     if reg is None:
         reg = _current_region()
@@ -118,11 +121,14 @@ def _on_compile(reg: str | None = None) -> None:
         from . import guard  # local: guard pulls in the breaker machinery
 
         guard.BREAKER.record_failure()
+    return reg
 
 
 def _duration_listener(event: str, duration: float, **kwargs) -> None:
     if event == _COMPILE_EVENT:
-        _on_compile()
+        reg = _on_compile()
+        tracer.event("jax_compile", fun=str(kwargs.get("fun_name", "?")),
+                     seconds=duration, region=reg)
 
 
 def _event_listener(event: str, **kwargs) -> None:

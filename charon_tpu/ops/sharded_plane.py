@@ -865,4 +865,4 @@ def sharded_pairing_check(p_x, p_y, q_x, q_y, mesh, plan=None) -> bool:
     kernel = _build_verify_step(mesh, Bd)
     ok = kernel(place(pad(p_x)), place(pad(p_y)), place(pad(q_x)),
                 place(pad(q_y)), place(mask))
-    return bool(np.asarray(ok).reshape(-1)[0])
+    return pairing_mod._wait_verdict(ok, "_local_check")

@@ -51,6 +51,7 @@ import threading
 
 import numpy as np
 
+from ..utils import tracer
 from .native_impl import NativeImpl
 from .types import PublicKey, Signature
 
@@ -166,25 +167,32 @@ class TPUImpl(NativeImpl):
         if not (len(public_keys) == len(datas) == len(signatures)):
             raise ValueError("length mismatch")
         n = len(public_keys)
-        if n < self.min_device_verify or not _on_device():
-            return NativeImpl.verify_batch(self, public_keys, datas,
-                                           signatures)
-        # Curve membership + infinity rejection run in rlc_verify_batch's
-        # bulk native decode; subgroup membership runs batched on device
-        # (endomorphism checks), matching the native per-item verifier's
-        # semantics.
-        from ..ops import plane_agg
+        device = n >= self.min_device_verify and _on_device()
+        # the whole call, host work and device waits alike: the span the
+        # bulk-verify path's ops/verify/* and ops/device_wait spans nest in
+        with tracer.start_span("tbls/verify_batch", n=n,
+                               path="device" if device else "native") as span:
+            if not device:
+                return NativeImpl.verify_batch(self, public_keys, datas,
+                                               signatures)
+            # Curve membership + infinity rejection run in
+            # rlc_verify_batch's bulk native decode; subgroup membership
+            # runs batched on device (endomorphism checks), matching the
+            # native per-item verifier's semantics.
+            from ..ops import plane_agg
 
-        try:
-            return plane_agg.rlc_verify_batch(
-                [bytes(pk) for pk in public_keys], [bytes(d) for d in datas],
-                [bytes(s) for s in signatures])
-        except _DEVICE_RUNTIME_ERRORS as exc:
-            if not self.fallback_on_device_error:
-                raise
-            _warn_device_fallback("verify_batch", exc)
-            return NativeImpl.verify_batch(self, public_keys, datas,
-                                           signatures)
+            try:
+                return plane_agg.rlc_verify_batch(
+                    [bytes(pk) for pk in public_keys],
+                    [bytes(d) for d in datas],
+                    [bytes(s) for s in signatures])
+            except _DEVICE_RUNTIME_ERRORS as exc:
+                if not self.fallback_on_device_error:
+                    raise
+                _warn_device_fallback("verify_batch", exc)
+                span.attrs["path"] = "native"
+                return NativeImpl.verify_batch(self, public_keys, datas,
+                                               signatures)
 
     def threshold_aggregate_verify_batch(self, batches, public_keys, datas):
         """Fused device pass: the RLC verification consumes the freshly

@@ -2,8 +2,8 @@
 
 The fused sigagg and verify graphs take minutes to compile cold and are
 identical run to run. `enable()` is called once by each entry point
-(app.assemble, bench.py, bench_stages.py, chip_smoke.py) before its first
-compile; nothing enables the cache as a side effect of an import.
+(app.assemble, bench.py, chip_smoke.py) before its first compile; nothing
+enables the cache as a side effect of an import.
 
 Placement: JAX itself reads JAX_COMPILATION_CACHE_DIR into its
 `jax_compilation_cache_dir` option, and enable() then uses exactly that

@@ -12,6 +12,12 @@ The in-process buffer doubles as the duty flight recorder: spans carry point
 trace-event JSON (`to_chrome_trace`/`write_chrome_trace`) loadable in
 Perfetto or chrome://tracing — one process row per trace (duty), one thread
 row per span name (pipeline step). See docs/observability.md.
+
+Every span is also a `jax.profiler.TraceAnnotation` of the same name and
+attributes for its lifetime, once JAX is loaded in the process (this module
+never imports it): while a profile is being taken, the program's spans land
+on the profiler's host timeline, on the clock of its device trace; with no
+profile running the annotation is one inactive TraceMe.
 """
 
 from __future__ import annotations
@@ -19,9 +25,10 @@ from __future__ import annotations
 import contextvars
 import hashlib
 import json
+import sys
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
 from typing import Any, Callable, Iterable
 
@@ -151,6 +158,16 @@ def attach_context(ctx: Any) -> str | None:
     return trace_id
 
 
+def _profiler_annotation(name: str, attrs: dict[str, Any]):
+    """The span's TraceAnnotation, or a no-op while JAX is not loaded (or is
+    still being imported): the tracer is imported by p2p, core and the CLI,
+    which must not pull JAX in."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return nullcontext()
+    return profiler.TraceAnnotation(name, **attrs)
+
+
 @contextmanager
 def start_span(name: str, **attrs: Any):
     trace_id = _current_trace.get()
@@ -164,7 +181,8 @@ def start_span(name: str, **attrs: Any):
     span = Span(trace_id, span_id, parent_id, name, time.time(), attrs=dict(attrs))
     token = _current_span.set(span)
     try:
-        yield span
+        with _profiler_annotation(name, attrs):
+            yield span
     finally:
         span.end = time.time()
         _current_span.reset(token)
@@ -184,6 +202,14 @@ def event(name: str, **attrs: Any) -> SpanEvent | None:
     if span is None:
         return None
     return span.add_event(name, **attrs)
+
+
+def set_attrs(**attrs: Any) -> None:
+    """Set attributes on the currently-open span (no-op outside one), for
+    what is known only inside a callee (e.g. a cache's miss count)."""
+    span = _current_span.get()
+    if span is not None:
+        span.attrs.update(attrs)
 
 
 def finished_spans() -> list[Span]:
