@@ -14,6 +14,8 @@ tbls facade (reference tbls/herumi.go:40-360).
 
 from __future__ import annotations
 
+import functools
+
 from . import fields as F
 
 # --- field op tables ---------------------------------------------------------
@@ -248,6 +250,18 @@ def _mul_full(ops, pt, k: int):
 # the standard h_eff values used by all BLS12-381 implementations).
 H_EFF_G1 = 0xD201000000010001
 H_EFF_G2 = 0xBC69F08F2EE75B3584C6A0EA91B352888E2A8E9145AD7689986FF031508FFE1329C2F178731DB956D82BF015D1212B02EC0EC69D7477C1AE954CBC06689F6A359894C0ADEBBF6B4E8020005AAA95551
+
+
+@functools.lru_cache(maxsize=1)
+def psi_consts():
+    """(cx, cy) of the G2 endomorphism psi = untwist-Frobenius-twist:
+    psi(x, y) = (cx·conj(x), cy·conj(y)), cx = 1/(1+u)^((p−1)/3) and
+    cy = 1/(1+u)^((p−1)/2) (RFC 9380 Appendix G.3). On Jacobian points
+    psi(X, Y, Z) = (cx·conj(X), cy·conj(Y), conj(Z))."""
+    xi = (1, 1)
+    cx = F.fq2_inv(F.fq2_pow(xi, (F.P - 1) // 3))
+    cy = F.fq2_inv(F.fq2_pow(xi, (F.P - 1) // 2))
+    return cx, cy
 
 
 def g2_clear_cofactor(pt):

@@ -5,8 +5,9 @@ ops/field Montgomery limb plane. The split follows SURVEY §7: SHA-256
 `expand_message_xmd` stays on host — bytes and hashing are host-shaped
 work — producing Fq2 field elements shipped to device as limb planes;
 the curve math (simplified SWU onto the 3-isogenous curve E', the
-3-isogeny back to E, and the 636-bit h_eff cofactor clear) runs
-branchlessly over the batch axis where throughput comes from width.
+3-isogeny back to E, and the cofactor clear by the ψ endomorphism of
+RFC 9380 Appendix G.3) runs branchlessly over the batch axis where
+throughput comes from width.
 
 Design notes:
   * Fq2 square roots use the complex method (valid because p ≡ 3 mod 4)
@@ -33,13 +34,13 @@ import numpy as np
 
 from ..crypto import fields as PF
 from ..crypto import hash_to_curve as HH
-from ..crypto.curve import H_EFF_G2
+from ..crypto.curve import psi_consts
 from ..utils import tracer
 from . import buckets as BK
 from . import field as F
 from . import pallas_plane as PP
 from . import tower as T
-from .curve import FQ2_OPS, add_unified, double, infinity_like, point_select
+from .curve import FQ2_OPS, add_unified, double
 
 DST_ETH = HH.DST_ETH
 
@@ -81,7 +82,6 @@ def _bits_arr(n: int) -> jnp.ndarray:
 
 _P14_BITS = _bits_arr((F.P_INT + 1) // 4)   # Fq sqrt exponent (p ≡ 3 mod 4)
 _P12_BITS = _bits_arr((F.P_INT - 1) // 2)   # Euler QR test exponent
-_H_EFF_BITS = _bits_arr(H_EFF_G2)           # 636-bit effective cofactor
 
 
 # ---------------------------------------------------------------------------
@@ -214,21 +214,80 @@ def _iso_map(x, y):
     return (Xj, Yj, Zj)
 
 
+# Cofactor clearing by RFC 9380 Appendix G.3 (Budroni–Pintore): on E2,
+#   [h_eff]P = [x²−x−1]P + [x−1]ψ(P) + ψ²(2P),   x = −X_ABS,
+# evaluated as the RFC's chain
+#   t1 = [x]P;  t2 = ψ(P) + t1;  t3 = [x]t2;  Q = ψ²(2P) + t3 − t2 − P.
+# It runs as a program over a small register file of points: every step is
+# R[dst] = op(map(R[a]), R[b]), map one of identity, negation, ψ, ψ², op one
+# of doubling (R[b] unused) and complete addition, executed by ONE lax.scan
+# whose switches run only the taken branch. Each [x]· is [|x|] of the
+# negated point, double-and-add from the implied leading bit: 63 doublings
+# and 5 additions, since |x| has Hamming weight 6.
+_ID, _NEG, _PSI, _PSI2 = range(4)
+_DBL, _ADD = range(2)
+_R_P, _R_T1, _R_T2, _R_T3, _R_Q = range(5)
+_N_PT_REGS = 5
+
+
+def _mul_x_steps(dst: int, src: int) -> list:
+    """dst <- [x]src = [|x|](−src), MSB-first from the implied leading one."""
+    steps = []
+    for i, bit in enumerate(bin(PF.X_ABS)[3:]):
+        steps.append((dst, src, _NEG, _DBL, dst) if i == 0
+                     else (dst, dst, _ID, _DBL, dst))
+        if bit == "1":
+            steps.append((dst, src, _NEG, _ADD, dst))
+    return steps
+
+
+_COFACTOR_TAPE = np.asarray(
+    _mul_x_steps(_R_T1, _R_P)                  # t1 = [x]P
+    + [(_R_T2, _R_P, _PSI, _ADD, _R_T1)]       # t2 = ψ(P) + t1
+    + _mul_x_steps(_R_T3, _R_T2)               # t3 = [x]t2
+    + [(_R_Q, _R_P, _PSI2, _DBL, _R_Q),        # Q = ψ²(2P) = 2ψ²(P)
+       (_R_Q, _R_T3, _ID, _ADD, _R_Q),         # Q = Q + t3
+       (_R_Q, _R_T2, _NEG, _ADD, _R_Q),        # Q = Q − t2
+       (_R_Q, _R_P, _NEG, _ADD, _R_Q)],        # Q = Q − P
+    dtype=np.int32)
+
+_PSI_CX, _PSI_CY = (_c2(c) for c in psi_consts())
+
+
+def _conj(a):
+    """Fq2 conjugation (the Frobenius): negate the c1 limb plane."""
+    return jnp.stack([a[..., 0, :], F.fq_neg(a[..., 1, :])], axis=-2)
+
+
+def _psi(p):
+    X, Y, Z = p
+    psiX, psiY = FQ2_OPS.mul_many([(_conj(X), jnp.asarray(_PSI_CX)),
+                                   (_conj(Y), jnp.asarray(_PSI_CY))])
+    return (psiX, psiY, _conj(Z))
+
+
+_MAPS = (lambda p: p,
+         lambda p: (p[0], F.fq2_neg(p[1]), p[2]),
+         _psi,
+         lambda p: _psi(_psi(p)))
+_OPS = (lambda p, q: double(FQ2_OPS, p),
+        lambda p, q: add_unified(FQ2_OPS, p, q))
+
+
 def _clear_cofactor(p):
-    """[h_eff]·P via double-and-add over the static 636-bit cofactor —
-    the same MSB-first select-scan shape as curve.scalar_mul, but the bits
-    are a host constant shared by every lane."""
-    acc0 = infinity_like(FQ2_OPS, p[0])
-    batch = p[0].shape[:-2]
+    """[h_eff]·P for Jacobian points P on E2 (any batch shape), by the
+    G.3 tape above."""
+    pp = jnp.stack(p)
+    regs = jnp.stack([pp] + [pp * 0] * (_N_PT_REGS - 1))
 
-    def step(acc, bit):
-        acc2 = double(FQ2_OPS, acc)
-        added = add_unified(FQ2_OPS, acc2, p)
-        mask = jnp.broadcast_to(bit.astype(bool), batch)
-        return point_select(FQ2_OPS, mask, added, acc2), None
+    def step(regs, ins):
+        dst, a, m, op, b = ins[0], ins[1], ins[2], ins[3], ins[4]
+        x = jax.lax.switch(m, _MAPS, tuple(regs[a]))
+        r = jax.lax.switch(op, _OPS, x, tuple(regs[b]))
+        return regs.at[dst].set(jnp.stack(r)), None
 
-    acc, _ = jax.lax.scan(step, acc0, _H_EFF_BITS)
-    return acc
+    regs, _ = jax.lax.scan(step, regs, jnp.asarray(_COFACTOR_TAPE))
+    return tuple(regs[_R_Q])
 
 
 @functools.lru_cache(maxsize=8)

@@ -49,7 +49,7 @@ import numpy as np
 from ..crypto import fields as PF
 from ..utils import faults, metrics, tracer
 from ..crypto.curve import (g1_generator, jac_add, jac_is_infinity, FqOps,
-                            Fq2Ops)
+                            Fq2Ops, psi_consts)
 from ..crypto.rlc import RLC_BITS, sample_randomizers
 from ..crypto.serialize import g1_to_bytes, g2_to_bytes
 from . import field as F
@@ -600,14 +600,6 @@ def _g2_plane_device(sigs: list[bytes], Bp: int,
 
 
 @functools.lru_cache(maxsize=1)
-def _psi_consts():
-    xi = (1, 1)
-    cx = PF.fq2_inv(PF.fq2_pow(xi, (PF.P - 1) // 3))
-    cy = PF.fq2_inv(PF.fq2_pow(xi, (PF.P - 1) // 2))
-    return cx, cy
-
-
-@functools.lru_cache(maxsize=1)
 def _g1_endo_consts():
     """(beta, sign) with phi(P) = (beta·x, y) == [sign·u²]P on G1 — found by
     the same search the native constant generator uses (native/gen_constants.py)."""
@@ -665,7 +657,7 @@ def _g2_subgroup_jit(X, Y, Z):
 
 def _g2_subgroup_core(X, Y, Z):
     S, W = X.shape[-2:]
-    cx, cy = _psi_consts()
+    cx, cy = psi_consts()
     B = X.shape[-2] * X.shape[-1]
     # psi: conjugate each coord (component-wise negate of c1), scale X and Y
     psiX = PP.fe_mul(_conj_plane(X), _const_plane(cx, 2, S, W), 2)
