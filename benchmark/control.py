@@ -5,11 +5,11 @@
 
 Runs the cell's set-up, closed loop and reference check with the reference
 put in the program's place and one guarantee broken: it verifies one partial
-in eight (from a seeded offset) instead of every partial. The check has to
-find it not correct on every seed. The reference answers far faster than
-the program, so its window is one whole slot (--seconds 0), as many sets as
-a run of the program compares. One JSON line per seed: correct and the
-counts compared. The benchmark's own runs never run this; it needs no chip.
+(of a slot: one aggregate) in eight, from a seeded offset, instead of every
+one. The check has to find it not correct on every seed; what it misses are
+the warm requests' bad partials. Its window is one whole slot (--seconds 0).
+One JSON line per seed: correct and the counts compared. The benchmark's own
+runs never run this; it needs no chip.
 """
 
 from __future__ import annotations
@@ -31,6 +31,7 @@ def main(argv=None) -> int:
     import jax
 
     cell = run.resolve(run.ROOT, args.workload, traced=False)
+    systems.program_system(cell.mix)  # stops on an unknown entry
     bad = 0
     for seed in args.seeds:
         system = systems.ReferenceSystem(control=True, seed=seed)

@@ -10,6 +10,7 @@ starts its submit call to the moment its answer is set.
 
 from __future__ import annotations
 
+import math
 import time
 from concurrent.futures import FIRST_COMPLETED, Future, wait
 from dataclasses import dataclass, field
@@ -120,8 +121,11 @@ def closed_loop(system, prepared, in_flight: int, seconds: float,
             outstanding[rec.future] = rec
             continue
         with jax.profiler.TraceAnnotation("bench.wait"):
+            # an endless loop (the warm-up's) waits with no timeout: a
+            # lock's wait takes none longer than the platform's time_t
             done, _ = wait(list(outstanding),
-                           timeout=end - now if now < end else None,
+                           timeout=end - now if now < end < math.inf
+                           else None,
                            return_when=FIRST_COMPLETED)
         for f in done:
             rec = outstanding.pop(f)

@@ -258,6 +258,27 @@ def mul(F, p, k: int):
     return out
 
 
+def lincomb(F, points, scalars):
+    """The sum of [k]p over the pairs, by Straus's method with 4-bit
+    windows: one chain of doublings shared by every point."""
+    tables = []
+    for p in points:
+        row = [p]
+        for _ in range(14):
+            row.append(add(F, row[-1], p))
+        tables.append(row)
+    ks = [k % R for k in scalars]
+    out = (F.one, F.one, F.zero)
+    for w in range(-(-max(k.bit_length() for k in ks) // 4) - 1, -1, -1):
+        for _ in range(4):
+            out = dbl(F, out)
+        for row, k in zip(tables, ks):
+            d = (k >> (4 * w)) & 15
+            if d:
+                out = add(F, out, row[d - 1])
+    return out
+
+
 def to_affine(F, p):
     if p[2] == F.zero:
         return None

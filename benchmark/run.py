@@ -10,13 +10,18 @@ benchmark/layers/<metric>.py with --trace 1), whose `read(run)` returns a
 number or None. Adding a cell, a mix or a metric adds files and entries and
 edits none.
 
-A run: set-up (keys and every set's inputs made from --seed; the mix's warm
-sets through the real path, which traces and compiles every shape the window
-uses), then the closed loop for --seconds and on to the end of the slot in
-progress (with --trace 1: the mix's `trace_requests` sets under the profiler
-instead), then every answered set compared with the reference. The last line
-of stdout is the JSON result. Without a TPU, or with fewer chips than the
-cell asks for, the run exits non-zero and prints no result.
+The mix's `entry` names the program's entry point that the closed loop
+drives (systems.ENTRIES): a peer set's bulk verify, or core/sigagg's
+aggregate+verify of one duty of a slot.
+
+A run: set-up (keys and every request's inputs made from --seed; the mix's
+warm requests through the real path, which traces and compiles every shape
+the window uses), then the closed loop for --seconds and on to the end of
+the slot in progress (with --trace 1: the mix's `trace_requests` requests
+under the profiler instead), then every answered request compared with the
+reference. The last line of stdout is the JSON result. Without a TPU, or
+with fewer chips than the cell asks for, the run exits non-zero and prints
+no result; so does a mix whose entry is unknown, before any set-up.
 """
 
 from __future__ import annotations
@@ -287,6 +292,9 @@ def main(argv=None) -> int:
     traced = bool(args.trace)
     cell = resolve(ROOT, args.workload, traced)
     chips = int(cell.workload["chips"])
+    import systems
+
+    program = systems.program_system(cell.mix)
 
     # the compile cache lives at a fixed path inside this checkout, with no
     # size cap, so that only a checkout's first run compiles: a cache placed
@@ -321,9 +329,7 @@ def main(argv=None) -> int:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     log(f"device {devices[0].device_kind} x{len(devices)}, using {chips}; "
         f"compile cache {cache}")
-    import systems
-
-    system = systems.ProgramSystem(chips)
+    system = program(chips)
     result = run_cell(cell, args.seed, args.seconds, traced, system, devices,
                       chips)
     for name, c in result["checks"].items():

@@ -1,8 +1,10 @@
-"""What the closed loop drives: the program's tbls entry point, or, for the
-control and the fault tests, the reference put in the program's place.
+"""What the closed loop drives: the program's tbls entry point that the
+mix's `entry` names (`ENTRIES`), or, for the control and the fault tests,
+the reference put in the program's place.
 
-A system turns a Request into the arguments of its call in set-up
-(`prepare`), and `submit(args)` returns a Future of the set's verdict.
+A system turns a request into the arguments of its call in set-up
+(`prepare`), and `submit(args)` returns a Future of the answer: a peer
+set's verdict, or a slot's (aggregates, verdict).
 """
 
 from __future__ import annotations
@@ -10,9 +12,10 @@ from __future__ import annotations
 import random
 from concurrent.futures import Future
 
+import inputs
 from reference import ref
 
-SAMPLE = 8  # the control verifies one partial in this many
+SAMPLE = 8  # the control verifies one partial (or aggregate) in this many
 
 
 def _resolved(fn, *args) -> Future:
@@ -66,15 +69,54 @@ class ProgramSystem:
         pass
 
 
-class ReferenceSystem:
-    """The reference in the program's place.
+class SlotSystem(ProgramSystem):
+    """core/sigagg's call for one duty of a slot
+    (tbls.threshold_aggregate_verify_submit, core/sigagg.py): threshold
+    partials per validator, the group pubkeys and the signing roots; its
+    Future resolves to (aggregates, verdict)."""
 
-    `control` makes it the control: it verifies one partial in SAMPLE, from
-    an offset drawn from `seed`, which breaks the guarantee that every
-    partial is verified. `fault` plants one of the faults a timed path can
-    have: "stale" (returns the previous request's answer), "half" (leaves
-    out the second half of the set), "altered" (alters the answer where it
-    is made)."""
+    def prepare(self, req):
+        from charon_tpu.tbls.types import Signature
+
+        return ([{i: Signature(s) for i, s in b.items()}
+                 for b in req.partials], list(req.pks), list(req.roots))
+
+    def submit(self, args) -> Future:
+        return self.tbls.threshold_aggregate_verify_submit(*args)
+
+
+# entry name in a mix -> the system that drives it
+ENTRIES = {
+    "tbls.verify_batch": ProgramSystem,
+    inputs.SLOT_ENTRY: SlotSystem,
+}
+
+
+def program_system(mix: dict):
+    """The class that drives the mix's entry; stops the run on an unknown
+    one."""
+    entry = mix.get("entry")
+    if entry not in ENTRIES:
+        raise SystemExit(f"benchmark: the mix's entry {entry!r} is not one "
+                         f"of {sorted(ENTRIES)}")
+    return ENTRIES[entry]
+
+
+class ReferenceSystem:
+    """The reference in the program's place, for peer sets and for slots.
+
+    A slot's aggregates are the Lagrange combinations (ref.lagrange_at_zero)
+    of each validator's partials, and its verdict whether every aggregate
+    is the group key's signature of the root.
+
+    `control` makes it the control: it verifies one partial (one aggregate)
+    in SAMPLE, from an offset drawn from `seed`, which breaks the guarantee
+    that every partial is verified. `fault` plants one of the faults a timed
+    path can have: "stale" (returns the previous request's answer), "half"
+    (leaves out the second half of the set; of a slot's validators, whose
+    aggregates are left as their first partial), "altered" (alters the
+    answer where it is made: the verdict of a set, one aggregate's bytes of
+    a slot)."""
 
     name = "reference"
 
@@ -94,9 +136,25 @@ class ReferenceSystem:
                  for i in range(self.rng.randrange(self.step), n, self.step))
         return not ok if self.fault == "altered" else ok
 
+    def _slot_answer(self, req) -> tuple[list[bytes], bool]:
+        n = req.size // 2 if self.fault == "half" else req.size
+        lam = ref.lagrange_at_zero(list(req.ids))
+        aggs = [ref.g2_compress(ref.lincomb(
+            ref.Fp2, [ref.g2_decompress(b[i]) for i in req.ids], lam))
+            for b in req.partials[:n]]
+        aggs += [b[req.ids[0]] for b in req.partials[n:]]
+        ok = all(ref.verifies(aggs[v], req.secrets[v], req.roots[v])
+                 for v in range(self.rng.randrange(self.step), n, self.step))
+        if self.fault == "altered":
+            aggs[0] = aggs[0][:-1] + bytes([aggs[0][-1] ^ 1])
+        return aggs, ok
+
     def submit(self, req) -> Future:
         def call():
-            ans = self._answer(req)
+            if isinstance(req, inputs.SlotRequest):
+                ans = self._slot_answer(req)
+            else:
+                ans = self._answer(req)
             if self.fault == "stale" and self.last is not None:
                 ans, self.last = self.last, ans
             else:

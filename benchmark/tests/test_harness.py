@@ -97,10 +97,126 @@ def test_a_new_config_mix_and_metric_need_no_edit(tmp_path):
     assert p.stdout.strip().endswith("ok"), p.stderr
 
 
-def _gen(seed, validators=256, **mix):
+def test_a_slot_cell_needs_only_data(tmp_path):
+    """The aggregate+verify slot becomes a cell by a workloads entry and an
+    end_to_end entry for aggregate_sigs_per_s that names it: the committed
+    mix, configuration and readers do the rest."""
+    root = tmp_path / "checkout"
+    shutil.copytree(os.path.join(ROOT, "benchmark"), root / "benchmark",
+                    ignore=shutil.ignore_patterns(".trace", "__pycache__"))
+    spec = json.loads(json.dumps(SPEC))
+    new_cell = "sigagg-dv4096-attester"
+    spec["workloads"].append({"name": new_cell, "config": "dv4096-4of6",
+                              "traffic": "sigagg-attester", "chips": 1,
+                              "why": "x"})
+    spec["end_to_end"].insert(0, {
+        "name": "aggregate_sigs_per_s", "unit": "sigs/s", "better": "higher",
+        "bound": 0.01, "source": "host_clock", "workloads": [new_cell]})
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    code = (
+        "import sys; sys.path.insert(0, sys.argv[1] + '/benchmark')\n"
+        "import jax, run, systems\n"
+        f"t = run.resolve(sys.argv[1], {new_cell!r}, True)\n"
+        "assert [m['name'] for m in t.metrics] == ['setup_compile_s', "
+        "'setup_trace_s'], t.metrics\n"
+        f"e = run.resolve(sys.argv[1], {new_cell!r}, False)\n"
+        "assert [m['name'] for m in e.metrics] == ['aggregate_sigs_per_s', "
+        "'setup_s']\n"
+        "assert systems.program_system(e.mix) is systems.SlotSystem\n"
+        "e.config['validators'] = 2048\n"
+        "e.mix['pool_slots'] = 1\n"
+        "res = run.run_cell(e, 3, 0.0, False, systems.ReferenceSystem(),\n"
+        "                   jax.devices(), 1, program=False)\n"
+        "assert res['correct'] and res['attempted'] == 1, res\n"
+        "assert set(res['metrics']) == {'aggregate_sigs_per_s', 'setup_s'}\n"
+        "print('ok')\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run([sys.executable, "-c", code, str(root)],
+                       capture_output=True, text=True, timeout=300, env=env)
+    assert p.stdout.strip().endswith("ok"), p.stderr
+
+
+def test_run_with_an_unknown_entry_stops_before_set_up(tmp_path):
+    root = tmp_path / "checkout"
+    shutil.copytree(os.path.join(ROOT, "benchmark"), root / "benchmark",
+                    ignore=shutil.ignore_patterns(".trace", "__pycache__"))
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
+    mix = root / "benchmark/traffic/parsigex-attester.json"
+    mix.write_text(json.dumps(dict(json.loads(mix.read_text()),
+                                   entry="tbls.nope")))
+    p = subprocess.run(
+        [sys.executable, str(root / "benchmark/run.py"), "--workload", CELL,
+         "--seed", "1", "--seconds", "1", "--trace", "0"],
+        capture_output=True, text=True, timeout=120,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert p.returncode != 0 and p.stdout.strip() == ""
+    assert "entry 'tbls.nope'" in p.stderr and "no TPU" not in p.stderr
+
+
+def _gen(seed, validators=256, mix_name=None, **mix):
     cell = run.resolve(ROOT, CELL, False)
     config = dict(cell.config, validators=validators)
-    return inputs.Generator(config, dict(cell.mix, **mix), seed)
+    base = cell.mix if mix_name is None else run.read_json(os.path.join(
+        ROOT, "benchmark", "traffic", f"{mix_name}.json"))
+    return inputs.Generator(config, dict(base, **mix), seed)
+
+
+# sha256 of the peer sets (warm and pool) that the parsigex mix's generator
+# made for these seeds and validator counts before the generator learnt to
+# make slots: the verify cell's requests must stay byte for byte the same
+VERIFY_SETS = {
+    (2**31 + 11, 256):
+        "dd109a1013863c67a404ab80c05bf69d4c6d22ee465328375482852f81269226",
+    (5, 2048):
+        "9dddb1e6bcda5c0b0bcebff0d6c86ba8ef67fac8f7b021fb19bb32d65c510b4d",
+    (2**31 + 5, 4096):
+        "5e5a3c8bb3cb9b307b2be95141f85b1e85872e7f5e98dd5ffa184979c36bc753",
+}
+
+
+@pytest.mark.parametrize("seed,validators", sorted(VERIFY_SETS))
+def test_verify_sets_are_byte_identical(seed, validators):
+    import hashlib
+
+    g = _gen(seed, validators)
+    h = hashlib.sha256()
+    for r in g.warm() + g.pool():
+        h.update(repr((r.index, r.slot, r.duty, r.peer, r.pks, r.roots,
+                       r.sigs, r.secrets, r.bad, r.last_of_slot)).encode())
+    assert h.hexdigest() == VERIFY_SETS[(seed, validators)]
+
+
+def test_slot_partials_combine_to_the_group_signature():
+    """Each slot's chosen partials Lagrange-combine to [secret]H(root) for
+    every validator whose partials are all over the root; the bad ones sit
+    in the warm slots' second halves; the id sets are the node's own share
+    and threshold - 1 peers, vary across slots and are fixed by the seed."""
+    a = _gen(2**31 + 3, mix_name="sigagg-attester", pool_slots=6)
+    ra = a.warm() + a.pool()
+    assert [r.bad for r in ra] == [1, 1, 1] + [0] * 7
+    assert all(r.last_of_slot and r.size == 256 // 32 for r in ra)
+    assert len({r.slot for r in ra}) == len(ra)
+    assert len({r.roots[0] for r in ra}) == len(ra)        # fresh roots
+    for r in ra:
+        assert len(r.ids) == 4 and 1 in r.ids
+        assert all(sorted(b) == list(r.ids) for b in r.partials)
+        assert r.pks == [ref.pubkey(k) for k in r.secrets]
+        lam = ref.lagrange_at_zero(list(r.ids))
+        good = []
+        for v, (b, k, m) in enumerate(zip(r.partials, r.secrets, r.roots)):
+            agg = ref.g2_compress(ref.lincomb(
+                ref.Fp2, [ref.g2_decompress(b[i]) for i in r.ids], lam))
+            good.append(agg == ref.sign(k, m))
+        assert good.count(False) == r.bad
+        assert all(good[:r.size // 2])
+    assert len({r.ids for r in ra}) > 1
+    b, c = (_gen(s, mix_name="sigagg-attester", pool_slots=6)
+            for s in (2**31 + 3, 7))
+    rb, rc = b.warm() + b.pool(), c.warm() + c.pool()
+    key = (lambda r: (r.ids, r.pks, r.roots, r.partials, r.bad))
+    assert [key(r) for r in ra] == [key(r) for r in rb]
+    assert [r.ids for r in ra] != [r.ids for r in rc]
+    assert [(r.size, r.bad) for r in ra] == [(r.size, r.bad) for r in rc]
 
 
 def _key(r):
@@ -159,6 +275,31 @@ def test_window_runs_to_the_end_of_the_slot():
     assert len(win.outcomes) == 2
     with pytest.raises(load.PoolExhausted):
         load.closed_loop(sys_, [(r, None) for r in reqs[:3]], 1, 0.0)
+
+
+def test_closed_loop_waits_for_answers_that_come_later():
+    """The program's slot entry answers from a worker thread: the warm-up's
+    endless loop and the window both wait for such futures."""
+    import math
+    import threading
+    from concurrent.futures import Future
+    from types import SimpleNamespace
+
+    import load
+
+    def submit(_args):
+        fut = Future()
+        threading.Timer(0.02, fut.set_result, (True,)).start()
+        return fut
+
+    reqs = [SimpleNamespace(size=1, last_of_slot=i % 2 == 1, index=i)
+            for i in range(8)]
+    sys_ = SimpleNamespace(submit=submit)
+    win = load.closed_loop(sys_, [(r, None) for r in reqs], 1, math.inf,
+                           max_requests=3, late_wait=5.0)
+    assert [o.answer for o in win.outcomes] == [True] * 3
+    win = load.closed_loop(sys_, [(r, None) for r in reqs], 1, 0.03)
+    assert len(win.completed) == 2 and win.rate() > 0
 
 
 def test_cluster_shares_interpolate_to_the_group_key():
